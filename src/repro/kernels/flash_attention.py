@@ -6,8 +6,13 @@ VMEM with running max/sum, so attention HBM traffic collapses to Q/K/V/O.
 Supports causal + sliding-window masks, logit softcap, GQA (q-head ->
 kv-head mapping in the BlockSpec index maps), forward + custom-vjp backward.
 
+Per-row softmax statistics (running max/sum, the saved log-sum-exp and the
+backward's ``delta``) are (rows, 1) columns inside the kernels; in HBM the
+saved ones are stored lane-replicated as (B*H, L, 128), the layout whose
+(bq, 128) blocks the TPU's (8, 128) tiling accepts.
+
 Validated in interpret mode against the pure-jnp oracle
-(`repro.models.attention.attn_forward`).
+(`repro.models.attention.attn_forward`) and compiled for v5e in the tests.
 """
 
 from __future__ import annotations
@@ -23,6 +28,8 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ["flash_attention"]
 
 NEG = -1e30
+#: lane width of the replicated per-row statistics in HBM
+_LANES = 128
 
 
 def _mask(iq, ik, bq, bk, causal, window):
@@ -59,22 +66,22 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_s, l_s, *,
             s = softcap * jnp.tanh(s / softcap)
         msk = _mask(iq, ik, bq, bk, causal, window)
         s = jnp.where(msk, s, NEG)
-        m_new = jnp.maximum(m_s[...], jnp.max(s, axis=1))
-        alpha = jnp.exp(m_s[...] - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l_new = l_s[...] * alpha + jnp.sum(p, axis=1)
+        m_new = jnp.maximum(m_s[...], jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_s[...] - m_new)                  # (bq, 1)
+        p = jnp.exp(s - m_new)
+        l_new = l_s[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
         v = v_ref[0].astype(jnp.float32)
         pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        acc[...] = acc[...] * alpha[:, None] + pv
+        acc[...] = acc[...] * alpha + pv
         m_s[...] = m_new
         l_s[...] = l_new
 
     @pl.when(ik == nk - 1)
     def _finish():
         l = jnp.maximum(l_s[...], 1e-30)
-        o_ref[0] = (acc[...] / l[:, None]).astype(o_ref.dtype)
-        lse_ref[0] = m_s[...] + jnp.log(l)
+        o_ref[0] = (acc[...] / l).astype(o_ref.dtype)
+        lse_ref[0] = jnp.broadcast_to(m_s[...] + jnp.log(l), (bq, _LANES))
 
 
 def _fwd(q, k, v, *, scale, causal, window, softcap, bq, bk, interpret):
@@ -101,18 +108,19 @@ def _fwd(q, k, v, *, scale, causal, window, softcap, bq, bk, interpret):
         ],
         out_specs=[
             pl.BlockSpec((1, bq, D), lambda bh, iq, ik: (bh, iq, 0)),
-            pl.BlockSpec((1, bq), lambda bh, iq, ik: (bh, iq)),
+            pl.BlockSpec((1, bq, _LANES), lambda bh, iq, ik: (bh, iq, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * Hq, Lq, D), q.dtype),
-            jax.ShapeDtypeStruct((B * Hq, Lq), jnp.float32),
+            jax.ShapeDtypeStruct((B * Hq, Lq, _LANES), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32),
-                        pltpu.VMEM((bq,), jnp.float32),
-                        pltpu.VMEM((bq,), jnp.float32)],
+                        pltpu.VMEM((bq, 1), jnp.float32),
+                        pltpu.VMEM((bq, 1), jnp.float32)],
         interpret=interpret,
+        name="flash_fwd",
     )(q2, k2, v2)
-    return out.reshape(B, Hq, Lq, D), lse.reshape(B, Hq, Lq)
+    return out.reshape(B, Hq, Lq, D), lse
 
 
 def _p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, iq, ik, *,
@@ -122,8 +130,8 @@ def _p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, iq, ik, *,
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
     do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0]
-    delta = delta_ref[0]
+    lse = lse_ref[0][:, :1]                    # (bq, 1)
+    delta = delta_ref[0][:, :1]
     sraw = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                preferred_element_type=jnp.float32) * scale
     if softcap is not None:
@@ -135,10 +143,10 @@ def _p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, iq, ik, *,
         dcap = jnp.ones_like(s)
     msk = _mask(iq, ik, bq, bk, causal, window)
     s = jnp.where(msk, s, NEG)
-    p = jnp.exp(s - lse[:, None])              # (bq, bk)
+    p = jnp.exp(s - lse)                       # (bq, bk)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    ds = p * (dp - delta[:, None]) * dcap * scale
+    ds = p * (dp - delta) * dcap * scale
     ds = jnp.where(msk, ds, 0.0)
     return q, k, do, p, ds
 
@@ -197,8 +205,8 @@ def _bwd(scale, causal, window, softcap, bq, bk, interpret, res, do):
     k2 = k.reshape(B * Hkv, Lk, D)
     v2 = v.reshape(B * Hkv, Lk, D)
     do2 = do.reshape(B * Hq, Lq, D)
-    lse2 = lse.reshape(B * Hq, Lq)
-    delta2 = delta.reshape(B * Hq, Lq)
+    delta2 = jnp.broadcast_to(delta.reshape(B * Hq, Lq, 1),
+                              (B * Hq, Lq, _LANES))
     nq, nk = Lq // bq, Lk // bk
 
     def kv_idx(bh, iq, ik):
@@ -214,14 +222,15 @@ def _bwd(scale, causal, window, softcap, bq, bk, interpret, res, do):
             pl.BlockSpec((1, bk, D), kv_idx),
             pl.BlockSpec((1, bk, D), kv_idx),
             pl.BlockSpec((1, bq, D), lambda bh, iq, ik: (bh, iq, 0)),
-            pl.BlockSpec((1, bq), lambda bh, iq, ik: (bh, iq)),
-            pl.BlockSpec((1, bq), lambda bh, iq, ik: (bh, iq)),
+            pl.BlockSpec((1, bq, _LANES), lambda bh, iq, ik: (bh, iq, 0)),
+            pl.BlockSpec((1, bq, _LANES), lambda bh, iq, ik: (bh, iq, 0)),
         ],
         out_specs=pl.BlockSpec((1, bq, D), lambda bh, iq, ik: (bh, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((B * Hq, Lq, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         interpret=interpret,
-    )(q2, k2, v2, do2, lse2, delta2)
+        name="flash_bwd_dq",
+    )(q2, k2, v2, do2, lse, delta2)
 
     # dk/dv are emitted PER Q-HEAD (grid walks q-heads) and group-summed
     # outside — avoids cross-head accumulation races under GQA.
@@ -235,8 +244,8 @@ def _bwd(scale, causal, window, softcap, bq, bk, interpret, res, do):
             pl.BlockSpec((1, bk, D), lambda bh, ik, iq: kv_idx(bh, iq, ik)),
             pl.BlockSpec((1, bk, D), lambda bh, ik, iq: kv_idx(bh, iq, ik)),
             pl.BlockSpec((1, bq, D), lambda bh, ik, iq: (bh, iq, 0)),
-            pl.BlockSpec((1, bq), lambda bh, ik, iq: (bh, iq)),
-            pl.BlockSpec((1, bq), lambda bh, ik, iq: (bh, iq)),
+            pl.BlockSpec((1, bq, _LANES), lambda bh, ik, iq: (bh, iq, 0)),
+            pl.BlockSpec((1, bq, _LANES), lambda bh, ik, iq: (bh, iq, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, bk, D), lambda bh, ik, iq: (bh, ik, 0)),
@@ -249,7 +258,8 @@ def _bwd(scale, causal, window, softcap, bq, bk, interpret, res, do):
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                         pltpu.VMEM((bk, D), jnp.float32)],
         interpret=interpret,
-    )(q2, k2, v2, do2, lse2, delta2)
+        name="flash_bwd_dkv",
+    )(q2, k2, v2, do2, lse, delta2)
     dq = dq.reshape(B, Hq, Lq, D)
     dk = dkh.reshape(B, Hq, Lk, D).reshape(B, Hkv, g, Lk, D).sum(
         axis=2).astype(k.dtype)
@@ -262,7 +272,7 @@ def _bwd(scale, causal, window, softcap, bq, bk, interpret, res, do):
                    nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def flash_attention(q, k, v, scale=None, causal=True, window=None,
                     softcap=None, block_q=256, block_k=256,
-                    interpret=True):
+                    interpret=False):
     """``q``: (B, Hq, Lq, D); ``k``/``v``: (B, Hkv, Lk, D); GQA via
     Hq % Hkv == 0.  Lq/Lk must divide the block sizes (caller pads)."""
     o, _ = _fwd(q, k, v, scale=scale or 1.0 / math.sqrt(q.shape[-1]),

@@ -2,7 +2,8 @@
 
 ``merge_blocks_device`` is the TPU path of the paper's §4 merge: block data
 already on device in log order (the chunked layout), output merged-cuboid
-buffers — one pack_rows kernel launch.  CPU tests run interpret=True.
+buffers — one pack_rows kernel launch, compiled for the TPU.  CPU tests pass
+interpret=True.
 """
 
 from __future__ import annotations
@@ -19,7 +20,7 @@ __all__ = ["merge_blocks_device", "split_merged"]
 
 
 def merge_blocks_device(plan: MergePlan, data: dict, *,
-                        interpret: bool = True) -> list:
+                        interpret: bool = False) -> list:
     """Execute ``plan`` on device.  ``data``: block_id -> array (block
     shape).  Returns the merged buffers (cluster order)."""
     width, src_rows, dst_rows, total_dst, src_off = plan_row_tables(plan)

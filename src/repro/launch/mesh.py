@@ -8,37 +8,27 @@ device state.  Single pod: 16 x 16 = 256 chips (v5e-256 class).  Multi-pod:
 
 from __future__ import annotations
 
-import inspect
-
 import jax
+from jax.sharding import AxisType
 
-__all__ = ["make_production_mesh", "make_host_mesh", "make_mesh_compat",
+__all__ = ["make_production_mesh", "make_host_mesh", "make_mesh",
            "DEVICES_PER_HOST"]
 
 #: v5e hosts drive 4 chips each
 DEVICES_PER_HOST = 4
 
 
-def make_mesh_compat(shape, axes) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` across jax versions.
-
-    ``jax.sharding.AxisType`` (and ``make_mesh``'s ``axis_types`` kwarg) was
-    introduced, renamed and removed across jax releases; pass explicit Auto
-    axis types only where the installed version supports them.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None) \
-        or getattr(jax.sharding, "AxisTypes", None)
-    kwargs = {}
-    if axis_type is not None and "axis_types" in \
-            inspect.signature(jax.make_mesh).parameters:
-        kwargs["axis_types"] = (axis_type.Auto,) * len(axes)
-    return jax.make_mesh(shape, axes, **kwargs)
+def make_mesh(shape, axes, devices=None) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with every axis ``Auto`` (GSPMD-partitioned).
+    ``devices`` defaults to ``jax.devices()``."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(shape=None, axes=("data", "model")) -> jax.sharding.Mesh:
@@ -46,4 +36,4 @@ def make_host_mesh(shape=None, axes=("data", "model")) -> jax.sharding.Mesh:
     n = len(jax.devices())
     if shape is None:
         shape = (1, n)
-    return make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)

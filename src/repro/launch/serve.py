@@ -14,6 +14,7 @@ import numpy as np
 from ..configs import get_config, get_smoke_config, list_archs
 from ..models import LM
 from ..serve import ServeEngine, cache_bytes
+from .compile_cache import use_compile_cache
 
 
 def main() -> None:
@@ -26,6 +27,7 @@ def main() -> None:
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if cfg.family == "audio":

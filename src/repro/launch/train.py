@@ -20,6 +20,7 @@ from ..data.pipeline import PipelineConfig, make_pipeline
 from ..distributed import sharding as shd
 from ..models import LM
 from ..train import OptimizerConfig, Trainer
+from .compile_cache import use_compile_cache
 from .mesh import make_host_mesh, make_production_mesh
 
 
@@ -40,6 +41,7 @@ def main() -> None:
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = LM(cfg)

@@ -14,7 +14,6 @@ import math
 import jax
 import jax.numpy as jnp
 
-from ..compat import shard_map
 from ..distributed.sharding import shard
 from .layers import rope, softcap
 from .params import ParamDef
@@ -151,7 +150,7 @@ def _flash_sharded(q, k, v, scale, causal, window, softcap, block):
 
     def call(a, b, c):
         return flash_attention(a, b, c, scale, causal, window, softcap,
-                               block, block, True)
+                               block, block)
 
     if ctx is None or ctx.mesh.size == 1:
         return call(q, k, v)
@@ -180,10 +179,10 @@ def _flash_sharded(q, k, v, scale, causal, window, softcap, block):
                 c = jax.lax.dynamic_slice_in_dim(c, start, kvn, axis=1)
             return call(a, b, c)
 
-    return shard_map(body, mesh=ctx.mesh,
-                         in_specs=(qspec, kspec, kspec),
-                         out_specs=qspec,
-                         axis_names=manual, check_vma=False)(q, k, v)
+    return jax.shard_map(body, mesh=ctx.mesh,
+                             in_specs=(qspec, kspec, kspec),
+                             out_specs=qspec,
+                             axis_names=manual, check_vma=False)(q, k, v)
 
 
 # -- cross attention ----------------------------------------------------------

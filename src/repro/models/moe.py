@@ -19,7 +19,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ..compat import shard_map
 from ..distributed.sharding import current_ctx, shard
 from .layers import mlp_defs, mlp_forward
 from .params import ParamDef
@@ -197,7 +196,7 @@ def _moe_forward_local(p, x, dims: MoEDims, ctx):
 
     bspec = P(dp_axes if len(dp_axes) > 1 else (dp_axes[0] if dp_axes
                                                 else None))
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P("model"), P("model"), P("model"), bspec),
         out_specs=(bspec, P()),

@@ -19,7 +19,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
 from ..distributed import sharding as shd
 from ..models.model import LM
 from .optimizer import OptimizerConfig, adamw_init, adamw_update
@@ -135,7 +134,7 @@ def make_train_step_reduce_once(model: LM, opt_cfg: OptimizerConfig,
                                              **opt_metrics)
 
     batch_spec = P(dp_axes if len(dp_axes) > 1 else dp_axes[0])
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P(), batch_spec),
         out_specs=(P(), P(), P()),

@@ -147,8 +147,8 @@ def test_async_checkpointer(tmp_path):
 def test_blocks_from_sharding_single_device():
     """On the 1-CPU container a trivial sharding gives one block."""
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((1,), ("x",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("x",))
     sh = NamedSharding(mesh, P())
     blocks = blocks_from_sharding((8, 4), sh, devices_per_host=4)
     assert len(blocks) == 1

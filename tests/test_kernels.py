@@ -32,6 +32,26 @@ def test_pack_rows_sweep(dtype, shape):
     np.testing.assert_array_equal(np.asarray(out), ref)
 
 
+def test_pack_rows_splits_long_tables_across_launches(monkeypatch):
+    """Row tables longer than one SMEM prefetch are copied in several
+    launches that chain the destination buffer."""
+    import jax
+    from repro.kernels import pack_blocks
+    monkeypatch.setattr(pack_blocks, "ROWS_PER_CALL", 5)
+    rng = np.random.default_rng(7)
+    n, w, m = 23, 128, 29
+    src = rng.standard_normal((n, w)).astype(np.float32)
+    perm = rng.permutation(n).astype(np.int32)
+    dst_rows = rng.choice(m, size=n, replace=False).astype(np.int32)
+    args = (jnp.asarray(src), jnp.asarray(perm), jnp.asarray(dst_rows))
+    kw = dict(n_dst_rows=m, width=w, interpret=True)
+    jaxpr = str(jax.make_jaxpr(lambda *a: pack_rows(*a, **kw))(*args))
+    assert jaxpr.count("pallas_call[") == 5          # ceil(23 / 5)
+    out = pack_rows(*args, **kw)
+    ref = pack_rows_ref(src, perm, dst_rows, n_dst_rows=m, width=w)
+    np.testing.assert_array_equal(np.asarray(out), ref)
+
+
 @pytest.mark.parametrize("dtype", [np.float32, ml_dtypes.bfloat16])
 @pytest.mark.parametrize("grid,chunk", [((4, 2), (8, 128)),
                                         ((2, 4), (16, 128)),

@@ -3,6 +3,7 @@ must match the baseline paths (f32-exact for flash; routing-exact for MoE),
 and the flash kernel must sweep shapes/dtypes against the oracle."""
 
 import dataclasses
+import functools
 import math
 
 import jax
@@ -82,7 +83,13 @@ def test_flash_kernel_grads():
                                    rtol=1e-3, atol=1e-4)
 
 
-def test_flash_model_path_matches_baseline_f32():
+def test_flash_model_path_matches_baseline_f32(monkeypatch):
+    # the model path compiles the kernel for the TPU; on CPU run the same
+    # kernel through the Pallas interpreter
+    from repro.kernels import flash_attention as fa
+    monkeypatch.setattr(fa, "flash_attention",
+                        functools.partial(fa.flash_attention,
+                                          interpret=True))
     p = materialize(attn_defs(64, 4, 2, 16, qkv_bias=True),
                     jax.random.key(0))
     rng = np.random.default_rng(0)
@@ -103,8 +110,8 @@ def test_flash_falls_back_on_indivisible_length():
 
 
 def test_moe_local_dispatch_matches_gather():
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"))
     cfg = get_smoke_config("deepseek-moe-16b")
     cfg = dataclasses.replace(
         cfg, moe=dataclasses.replace(cfg.moe, capacity_factor=16.0))

@@ -5,7 +5,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compat import shard_map
 from repro.configs import get_smoke_config
 from repro.data.pipeline import PipelineConfig, Prefetcher, SyntheticTokens
 from repro.distributed.collectives import (compressed_psum_tree,
@@ -137,17 +136,17 @@ def test_int8_compression_roundtrip():
 
 
 def test_compressed_psum_inside_shard_map():
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((1,), ("pod",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("pod",))
     grads = {"w": jnp.ones((8, 8), jnp.float32) * 0.3}
 
     def f(g):
         out, fb = compressed_psum_tree(g, "pod")
         return out, fb
 
-    out, fb = shard_map(f, mesh=mesh,
-                            in_specs=(jax.sharding.PartitionSpec(),),
-                            out_specs=jax.sharding.PartitionSpec())(grads)
+    out, fb = jax.shard_map(f, mesh=mesh,
+                                in_specs=(jax.sharding.PartitionSpec(),),
+                                out_specs=jax.sharding.PartitionSpec())(grads)
     np.testing.assert_allclose(np.asarray(out["w"]), 0.3, rtol=0.02)
 
 
