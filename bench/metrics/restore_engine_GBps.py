@@ -1,0 +1,10 @@
+"""The engine's bandwidth in a restore: summed ``bytes`` of the program's
+``repro.read.engine`` spans over their summed self time."""
+
+from bench.progspans import gbps
+
+
+def read(run):
+    if "bench.restore" not in run.spans:
+        return None
+    return gbps(run, "repro.read.engine")

@@ -50,6 +50,7 @@ from ..core.blocks import Block
 from ..core.layouts import plan_layout
 from ..core.policy import (ACCESS_PRIOR_NAME, AccessLog, AccessRecord,
                            LayoutPolicy)
+from ..core.spans import span
 from ..io.engine import IOEngine
 from ..io.reader import Dataset, ReadStats
 from .blocks_map import blocks_from_sharding, flatten_pytree, unflatten_like
@@ -66,7 +67,6 @@ class SaveStats:
     bytes: int
     num_chunks: int
     num_original_blocks: int
-    per_var_seconds: dict
 
 
 @dataclasses.dataclass
@@ -189,12 +189,17 @@ class CheckpointManager:
         hosts).  ``prior``: seed this save's ``strategy="auto"`` decisions
         from a previous run's restore history (per-call override of the
         manager-level ``prior=``)."""
+        with span("repro.save") as s:
+            stats = self._save(step, tree, shardings, block_map, prior)
+            s.set_metadata(bytes=stats.bytes)
+        return stats
+
+    def _save(self, step, tree, shardings, block_map, prior) -> SaveStats:
         t0 = time.perf_counter()
         d = self.step_dir(step)
         flat = flatten_pytree(tree)
         flat_sh = flatten_pytree(shardings) if shardings is not None else {}
         ds = Dataset.create(d, engine=self.engine, clock=self._clock)
-        per_var = {}
         policy_info = {}
         total_bytes = 0
         n_chunks = 0
@@ -202,66 +207,69 @@ class CheckpointManager:
         scalars = {}
         vars_meta = {}
         for name, arr in flat.items():
-            arr = np.asarray(arr)
-            tv = time.perf_counter()
+            with span("repro.save.d2h"):
+                arr = np.asarray(arr)
             if arr.ndim == 0:
                 scalars[name] = {"dtype": arr.dtype.name,
                                  "value": arr.item()}
                 continue
-            if block_map and name in block_map:
-                blocks = list(block_map[name])
-            elif name in flat_sh and flat_sh[name] is not None:
-                blocks = blocks_from_sharding(arr.shape, flat_sh[name],
-                                              self.devices_per_host)
-            else:
-                blocks = [Block((0,) * arr.ndim, arr.shape, owner=0,
-                                block_id=0)]
-            hosts = max(b.owner for b in blocks) + 1
-            data = {b.block_id: arr[b.slices()] for b in blocks}
+            with span("repro.save.plan"):
+                if block_map and name in block_map:
+                    blocks = list(block_map[name])
+                elif name in flat_sh and flat_sh[name] is not None:
+                    blocks = blocks_from_sharding(arr.shape, flat_sh[name],
+                                                  self.devices_per_host)
+                else:
+                    blocks = [Block((0,) * arr.ndim, arr.shape, owner=0,
+                                    block_id=0)]
+                hosts = max(b.owner for b in blocks) + 1
+                if self.strategy == "auto":
+                    # a save stages from memory: no gather term, only the
+                    # write-side build cost vs the expected restore mix
+                    decision = self.layout_policy(prior).choose_layout(
+                        name, blocks, arr.shape, num_procs=hosts,
+                        procs_per_node=self.hosts_per_node, align=self.align,
+                        now=self._clock())
+                    plan = decision.layout
+                    policy_info[name] = decision.to_json()
+                else:
+                    scheme = None
+                    if self.reorg_scheme is not None:
+                        scheme = (tuple(self.reorg_scheme[:arr.ndim])
+                                  + (1,) * max(0, arr.ndim
+                                               - len(self.reorg_scheme)))
+                    plan = plan_layout(self.strategy, blocks,
+                                       num_procs=hosts,
+                                       procs_per_node=self.hosts_per_node,
+                                       global_shape=arr.shape,
+                                       reorg_scheme=scheme)
+                wplan = ds.plan_write(name, plan, arr.dtype, align=self.align)
             vars_meta[name] = {
                 "shape": [int(s) for s in arr.shape],
                 "dtype": arr.dtype.name,
                 "blocks": [[[int(v) for v in b.lo], [int(v) for v in b.hi],
                             int(b.owner), int(b.block_id)] for b in blocks]}
-            if self.strategy == "auto":
-                # a save stages from memory: no gather term, only the
-                # write-side build cost vs the expected restore mix
-                decision = self.layout_policy(prior).choose_layout(
-                    name, blocks, arr.shape, num_procs=hosts,
-                    procs_per_node=self.hosts_per_node, align=self.align,
-                    now=self._clock())
-                plan = decision.layout
-                policy_info[name] = decision.to_json()
-            else:
-                scheme = None
-                if self.reorg_scheme is not None:
-                    scheme = (tuple(self.reorg_scheme[:arr.ndim])
-                              + (1,) * max(0, arr.ndim
-                                           - len(self.reorg_scheme)))
-                plan = plan_layout(self.strategy, blocks, num_procs=hosts,
-                                   procs_per_node=self.hosts_per_node,
-                                   global_shape=arr.shape,
-                                   reorg_scheme=scheme)
             # index.json is re-committed per variable, so a crash mid-save
             # leaves a readable prefix of the checkpoint
-            ds.write(name, plan, arr.dtype, data, align=self.align)
-            per_var[name] = time.perf_counter() - tv
+            ds.write_planned(wplan, {b.block_id: arr[b.slices()]
+                                     for b in blocks})
             total_bytes += arr.nbytes
             n_chunks += plan.num_chunks
             n_blocks += len(blocks)
-        ds.close()
-        manifest = {"step": step, "strategy": self.strategy,
-                    "scalars": scalars,
-                    "variables": sorted(k for k in flat if k not in scalars)}
-        if policy_info:
-            manifest["policy"] = policy_info
-        with open(os.path.join(d, MANIFEST), "w") as f:
-            json.dump(manifest, f)
+        with span("repro.save.manifest"):
+            ds.close()
+            manifest = {"step": step, "strategy": self.strategy,
+                        "scalars": scalars,
+                        "variables": sorted(k for k in flat
+                                            if k not in scalars)}
+            if policy_info:
+                manifest["policy"] = policy_info
+            with open(os.path.join(d, MANIFEST), "w") as f:
+                json.dump(manifest, f)
         self._retain()
         stats = SaveStats(step=step, seconds=time.perf_counter() - t0,
                           bytes=total_bytes, num_chunks=n_chunks,
-                          num_original_blocks=n_blocks,
-                          per_var_seconds=per_var)
+                          num_original_blocks=n_blocks)
         if self.trace is not None:
             self.trace.record(
                 "ckpt_save", seconds=stats.seconds, nbytes=total_bytes,
@@ -272,8 +280,10 @@ class CheckpointManager:
 
     def _retain(self) -> None:
         steps = self.steps()
-        for s in steps[:-self.keep] if self.keep else []:
-            shutil.rmtree(self.step_dir(s), ignore_errors=True)
+        old = steps[:-self.keep] if self.keep else []
+        with span("repro.save.retain", dirs=len(old)):
+            for s in old:
+                shutil.rmtree(self.step_dir(s), ignore_errors=True)
 
     # -- restore -----------------------------------------------------------------
     def restore(self, step: int, template=None,
@@ -288,6 +298,10 @@ class CheckpointManager:
         candidate set vectorized and are replayed with ``read_planned``.
         ``RestoreStats.per_var`` carries each variable's merged stats.
         """
+        with span("repro.restore"):
+            return self._restore(step, template, target_blocks, engine)
+
+    def _restore(self, step, template, target_blocks, engine):
         d = self.step_dir(step)
         with open(os.path.join(d, MANIFEST)) as f:
             manifest = json.load(f)
@@ -295,13 +309,15 @@ class CheckpointManager:
         flat = {}
         ds = None
         if manifest["variables"]:
-            ds = Dataset.open(d, engine=engine if engine is not None
-                              else self.engine)
+            with span("repro.read.open"):
+                ds = Dataset.open(d, engine=engine if engine is not None
+                                  else self.engine)
         for name in manifest["variables"]:
             shape = ds.index.var_shape(name)
             full = Block((0,) * len(shape), shape)
             tp = time.perf_counter()
-            cand = ds.index.spatial_index(name).query(full.lo, full.hi)
+            with span("repro.read.probe"):
+                cand = ds.index.spatial_index(name).query(full.lo, full.hi)
             vstats = ReadStats(probe_seconds=time.perf_counter() - tp)
             regions = (list(target_blocks[name])
                        if target_blocks and name in target_blocks else [full])
@@ -321,7 +337,8 @@ class CheckpointManager:
             agg.per_var[name] = vstats
         if ds is not None:
             ds.close()
-        self.access_log.flush()
+        with span("repro.read.telemetry"):
+            self.access_log.flush()
         for name, rec in manifest["scalars"].items():
             flat[name] = np.asarray(rec["value"], dtype=rec["dtype"])
         if self.trace is not None:
@@ -345,9 +362,10 @@ class CheckpointManager:
         the history ``strategy="auto"`` saves consult.  Telemetry never
         breaks a restore."""
         try:
-            self.access_log.append(
-                AccessRecord.from_stats(name, "restore", region, shape, st,
-                                        ts=self._clock()))
+            with span("repro.read.telemetry"):
+                self.access_log.append(
+                    AccessRecord.from_stats(name, "restore", region, shape,
+                                            st, ts=self._clock()))
         except Exception:               # noqa: BLE001 — telemetry only
             pass
 

@@ -44,6 +44,7 @@ import numpy as np
 
 from ..core.blocks import Block
 from ..core.layouts import LayoutPlan
+from ..core.spans import span
 from .format import DatasetIndex, VarRows, align_up
 from .spatial import aabb_mask
 
@@ -143,7 +144,8 @@ def build_read_plan(index: DatasetIndex, var: str, region: Block,
     ndim = region.ndim
     t0 = time.perf_counter()
     if candidates is None:
-        cand = index.spatial_index(var).query(region.lo, region.hi)
+        with span("repro.read.probe"):
+            cand = index.spatial_index(var).query(region.lo, region.hi)
     else:
         # narrowing needs only the plain AABB test — don't force an index
         # build on paths that deliberately bypass it

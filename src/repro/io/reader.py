@@ -66,6 +66,7 @@ from ..core.layouts import ChunkPlan, LayoutPlan
 from ..core.policy import AccessLog, AccessRecord, LayoutPolicy
 from ..core.read_patterns import best_decompositions, decompose_region
 from ..core.cost_model import observe_reorg_overhead
+from ..core.spans import span
 from .engine import (IOEngine, SubfileStore, WriteStats, assemble_chunk,
                      get_engine, resolve_engine, scatter_row)
 from .format import ChunkRecord, DatasetIndex, INDEX_NAME, extent_checksum
@@ -293,9 +294,10 @@ class Dataset:
         if not self._telemetry:
             return
         try:
-            self.access_log.append(AccessRecord.from_stats(
-                var, kind, region, self.index.var_shape(var), stats,
-                tenant=tenant, ts=self._clock()))
+            with span("repro.read.telemetry"):
+                self.access_log.append(AccessRecord.from_stats(
+                    var, kind, region, self.index.var_shape(var), stats,
+                    tenant=tenant, ts=self._clock()))
         except Exception:               # noqa: BLE001 — telemetry only
             pass
         if self._trace is not None:
@@ -423,21 +425,23 @@ class Dataset:
         if encoded is not None:
             buffers = [encoded[int(cid)] for cid in plan.chunk_ids]
         else:
-            buffers = [assemble_chunk(plan.layout.chunks[int(cid)], data,
-                                      plan.dtype)
-                       for cid in plan.chunk_ids]
+            with span("repro.write.assemble"):
+                buffers = [assemble_chunk(plan.layout.chunks[int(cid)],
+                                          data, plan.dtype)
+                           for cid in plan.chunk_ids]
         assemble_seconds = time.perf_counter() - t0
 
         t0 = time.perf_counter()
-        for sf, size in plan.file_sizes.items():
-            self._store.ensure_size(sf, size)
-        eng.write_plan(plan, buffers, self._store)
-        if fsync:
-            self._store.fsync()
+        with span("repro.write.engine", bytes=plan.bytes_total):
+            for sf, size in plan.file_sizes.items():
+                self._store.ensure_size(sf, size)
+            eng.write_plan(plan, buffers, self._store)
+            if fsync:
+                self._store.fsync()
         write_seconds = time.perf_counter() - t0
 
         # commit: records enter the index only after every extent landed
-        with self._lock:
+        with self._lock, span("repro.write.commit"):
             if plan.var not in self.index.variables:
                 self.index.add_variable(plan.var, plan.global_shape,
                                         plan.dtype, plan.strategy)
@@ -502,11 +506,12 @@ class Dataset:
                                       data, fsync=fsync)
         dtype = np.dtype(dtype)
         t0 = time.perf_counter()
-        enc = [np.frombuffer(
-                   encode(codec, np.ascontiguousarray(
-                       assemble_chunk(cp, data, dtype))),
-                   dtype=np.uint8)
-               for cp in layout.chunks]
+        with span("repro.write.assemble"):
+            enc = [np.frombuffer(
+                       encode(codec, np.ascontiguousarray(
+                           assemble_chunk(cp, data, dtype))),
+                       dtype=np.uint8)
+                   for cp in layout.chunks]
         encode_seconds = time.perf_counter() - t0
         sizes = np.asarray([b.nbytes for b in enc], dtype=np.int64)
         with self._lock:
@@ -528,9 +533,10 @@ class Dataset:
                   coalesce_gap: int = 0) -> ReadPlan:
         """Plan (but do not execute) a region read; see
         :func:`repro.io.planner.build_read_plan`."""
-        return build_read_plan(self.index, var, region,
-                               candidates=candidates,
-                               coalesce_gap=coalesce_gap)
+        with span("repro.read.plan"):
+            return build_read_plan(self.index, var, region,
+                                   candidates=candidates,
+                                   coalesce_gap=coalesce_gap)
 
     def read_planned(self, plan: ReadPlan, out: np.ndarray | None = None,
                      engine: str | IOEngine | None = None,
@@ -559,7 +565,8 @@ class Dataset:
                           predicted_seconds=choice.predicted_seconds
                           if choice else 0.0)
         t0 = time.perf_counter()
-        eng.read_plan(plan, self._store, out)
+        with span("repro.read.engine", bytes=plan.bytes_needed):
+            eng.read_plan(plan, self._store, out)
         stats.seconds = time.perf_counter() - t0
         if note_drift:
             self._note_drift(choice, stats.seconds)
@@ -622,10 +629,12 @@ class Dataset:
              candidates: np.ndarray | None = None,
              engine: str | IOEngine | None = None) -> tuple:
         """Assemble ``region`` of ``var``. Returns (array, ReadStats)."""
-        plan = self.plan_read(var, region, candidates=candidates)
-        arr, stats = self.read_planned(plan, engine=engine)
-        stats.seconds += plan.probe_seconds + plan.plan_seconds
-        self._record_access(var, region, stats, trace_kind="read")
+        with span("repro.read") as s:
+            plan = self.plan_read(var, region, candidates=candidates)
+            arr, stats = self.read_planned(plan, engine=engine)
+            stats.seconds += plan.probe_seconds + plan.plan_seconds
+            self._record_access(var, region, stats, trace_kind="read")
+            s.set_metadata(bytes=stats.bytes_read)
         return arr, stats
 
     def read_decomposed(self, var: str, region: Block,

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from ..core.spans import span
 from ..distributed import sharding as shd
 from ..models.model import LM
 from .optimizer import OptimizerConfig, adamw_init, adamw_update
@@ -194,11 +195,13 @@ class Trainer:
             log_every: int = 10, log_fn=print):
         history = []
         for _ in range(num_steps):
-            batch = next(self.data)
+            with span("repro.train.batch"):
+                batch = next(self.data)
             t0 = time.perf_counter()
-            params, opt_state, metrics = self._step_fn(params, opt_state,
-                                                       batch)
-            jax.block_until_ready(metrics["loss"])
+            with span("repro.train.step"):
+                params, opt_state, metrics = self._step_fn(params, opt_state,
+                                                           batch)
+                jax.block_until_ready(metrics["loss"])
             dt = time.perf_counter() - t0
             self.state.step += 1
             self.state.step_times.append(dt)
