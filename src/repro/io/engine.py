@@ -50,10 +50,11 @@ invalidation, all thread-safe for decomposed reads and staging writers.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 import threading
 import time
-from concurrent.futures import ThreadPoolExecutor
+from concurrent.futures import ThreadPoolExecutor, wait
 from typing import Mapping, Sequence
 
 import numpy as np
@@ -81,6 +82,14 @@ DEFAULT_QUEUE_DEPTH = 8
 
 #: default queue depth of the uring engine (SQEs in flight per batch)
 DEFAULT_URING_DEPTH = 16
+
+#: least bytes of one piece of the memmap engine's read copy; a plan of
+#: fewer than two pieces' bytes copies serially on the calling thread
+#: (chosen by a sweep on a TPU v5e host: PERF.md, Findings, PR 14)
+COPY_PIECE_BYTES = 4 << 20
+
+#: most threads of the shared pool the memmap engine copies on (same sweep)
+COPY_WORKERS_MAX = 12
 
 #: registered fixed-buffer slot size: depth x this much memory is pinned
 #: (counted against RLIMIT_MEMLOCK — containers commonly cap it at 8 MiB,
@@ -278,6 +287,95 @@ def scatter_row(plan: ReadPlan, row: int, span: np.ndarray,
 #: pre-ISSUE-7 private name, kept for the engine subclasses below
 _scatter = scatter_row
 
+_copy_pool: tuple | None = None       # (pid, ThreadPoolExecutor)
+_copy_pool_lock = threading.Lock()
+
+
+def copy_pool() -> ThreadPoolExecutor:
+    """The process-wide pool the memmap engine copies on: made on first
+    use, and made again in a forked child, which has none of its parent's
+    threads.  Its workers only copy and never submit to it, so callers that
+    are themselves threads (decomposed readers, the read service) cannot
+    deadlock on it, and it bounds the copy threads of all of them."""
+    global _copy_pool
+    pid = os.getpid()
+    ent = _copy_pool
+    if ent is None or ent[0] != pid:
+        with _copy_pool_lock:
+            ent = _copy_pool
+            if ent is None or ent[0] != pid:
+                width = min(len(os.sched_getaffinity(0)), COPY_WORKERS_MAX)
+                ent = _copy_pool = (pid, ThreadPoolExecutor(
+                    max_workers=width, thread_name_prefix="memmap-copy"))
+    return ent[1]
+
+
+def _copy(dst: np.ndarray, src: np.ndarray) -> None:
+    dst[...] = src
+
+
+def _run_piece(jobs) -> None:
+    for job in jobs:
+        job()
+
+
+def _copy_pieces(plan: ReadPlan, store: SubfileStore,
+                 out: np.ndarray) -> list:
+    """Cut ``plan``'s copy into pieces of about ``COPY_PIECE_BYTES``, each
+    a list of thunks.  A large raw row is cut along the first axis of its
+    intersection with more than one element, into sub-slabs of the strided
+    view :func:`scatter_row` assigns; chunks are disjoint, so the pieces
+    write disjoint parts of ``out``.  A compressed row decodes whole and is
+    a piece of its own; small raw rows are batched."""
+    piece = COPY_PIECE_BYTES
+    itemsize = plan.dtype.itemsize
+    pieces, batch, batched = [], [], 0
+    for row in range(plan.num_chunks):
+        raw = store.read_map(int(plan.subfiles[row]))
+        span = raw[plan.file_lo[row]:plan.file_hi[row]]
+        whole = functools.partial(scatter_row, plan, row, span, out)
+        if plan.codecs is not None and plan.codecs[row] != CODEC_NONE:
+            pieces.append([whole])
+            continue
+        ishape = [int(s) for s in plan.inter_his[row] - plan.inter_los[row]]
+        nbytes = int(np.prod(ishape)) * itemsize
+        axis = next((a for a, n in enumerate(ishape) if n > 1), 0)
+        cuts = min(ishape[axis], nbytes // piece)
+        if cuts < 2:
+            batch.append(whole)
+            batched += nbytes
+            if batched >= piece:
+                pieces.append(batch)
+                batch, batched = [], 0
+            continue
+        view = np.lib.stride_tricks.as_strided(
+            span.view(plan.dtype), shape=tuple(ishape),
+            strides=tuple(int(s) * itemsize for s in plan.strides[row]))
+        dst = out[plan.out_slices(row)]
+        lead = (slice(None),) * axis
+        edges = [ishape[axis] * i // cuts for i in range(cuts + 1)]
+        for lo, hi in zip(edges[:-1], edges[1:]):
+            sub = lead + (slice(lo, hi),)
+            pieces.append([functools.partial(_copy, dst[sub], view[sub])])
+    if batch:
+        pieces.append(batch)
+    return pieces
+
+
+def _run_pieces(pieces: list) -> None:
+    """Run every piece on the shared pool and wait for all of them before
+    the first failure surfaces: no worker may still write into the output
+    once the read has returned or raised."""
+    pool = copy_pool()
+    futures = []
+    try:
+        for p in pieces:
+            futures.append(pool.submit(_run_piece, p))
+    finally:
+        wait(futures)
+    for f in futures:
+        f.result()
+
 
 def _flat_bytes(buf: np.ndarray) -> np.ndarray:
     return np.ascontiguousarray(buf).reshape(-1).view(np.uint8)
@@ -289,7 +387,9 @@ class IOEngine:
     name = "abstract"
 
     def read_plan(self, plan: ReadPlan, store: SubfileStore,
-                  out: np.ndarray) -> None:
+                  out: np.ndarray) -> int | None:
+        """Gather ``plan`` into ``out``.  An engine that splits the copy
+        over threads returns the bytes they copied."""
         raise NotImplementedError
 
     def write_plan(self, plan: WritePlan, buffers: Sequence[np.ndarray],
@@ -305,10 +405,20 @@ class MemmapEngine(IOEngine):
     name = "memmap"
 
     def read_plan(self, plan, store, out):
+        """Copy ``plan`` out of the page cache.  A plan of at least two
+        pieces' bytes is split into byte-balanced pieces on the shared
+        :func:`copy_pool`; returns the bytes it copied there, or 0 where
+        the plan ran serially on the calling thread."""
+        if plan.bytes_needed >= 2 * COPY_PIECE_BYTES:
+            pieces = _copy_pieces(plan, store, out)
+            if len(pieces) > 1:
+                _run_pieces(pieces)
+                return plan.bytes_needed
         for row in range(plan.num_chunks):
             raw = store.read_map(int(plan.subfiles[row]))
             span = raw[plan.file_lo[row]:plan.file_hi[row]]
             _scatter(plan, row, span, out)
+        return 0
 
     def write_plan(self, plan, buffers, store):
         for row in range(plan.num_chunks):

@@ -565,8 +565,9 @@ class Dataset:
                           predicted_seconds=choice.predicted_seconds
                           if choice else 0.0)
         t0 = time.perf_counter()
-        with span("repro.read.engine", bytes=plan.bytes_needed):
-            eng.read_plan(plan, self._store, out)
+        with span("repro.read.engine", bytes=plan.bytes_needed) as s:
+            split = eng.read_plan(plan, self._store, out)
+            s.set_metadata(split_bytes=split or 0)
         stats.seconds = time.perf_counter() - t0
         if note_drift:
             self._note_drift(choice, stats.seconds)
